@@ -1,6 +1,6 @@
 """Pallas TPU flash-attention (prefill/training) kernel.
 
-TPU-native design (DESIGN.md §2 — adapted from the GPU flash algorithm):
+TPU-native design (adapted from the GPU flash algorithm):
 
 * Grid = (batch, q_heads, q_blocks, kv_blocks); the kv dimension is the
   innermost ("arbitrary") axis so the online-softmax state lives in VMEM
@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -153,7 +151,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,45 +1,51 @@
-"""Jit'd dispatch wrappers around the kernels.
+"""Dispatch from the models to the kernels; the platform picks the lowering.
 
-``set_backend()`` / the ``REPRO_KERNEL_BACKEND`` env var select the lowering:
+  * on TPU — the compiled Pallas kernels (``flash_attention``,
+    ``decode_attention``, ``ssd_scan``);
+  * anywhere else — the blocked jnp algorithms of ``ref.py``, which have the
+    kernels' memory profile and lower on every backend.
 
-  * ``pallas``   — the Pallas TPU kernels (``interpret=True`` automatically on
-                   CPU so tests can run anywhere).
-  * ``blocked``  — pure-jnp flash/chunked algorithms (ref.py).  Default for
-                   dry-runs: same memory profile as the kernels, lowers on any
-                   backend, keeps HLO clean for cost analysis.
-  * ``naive``    — full-materialisation oracles (tiny shapes/tests only).
-
-Models call only these entry points, so the backend choice is a launcher
-concern (the TPU launcher sets ``pallas``; dry-run and CI set ``blocked``).
+Interpret mode is never chosen here: tests that exercise a Pallas kernel off
+the chip call it with ``interpret=True`` themselves.  The full-materialisation
+oracles in ``ref.py`` are the tests' reference only.
 """
 
 from __future__ import annotations
 
-import functools
-import os
-from typing import Literal
-
 import jax
+from jax.sharding import PartitionSpec as P
 
-from . import ref
+from repro.sharding import ctx as shard_ctx
 
-Backend = Literal["pallas", "blocked", "naive"]
-_BACKEND: Backend = os.environ.get("REPRO_KERNEL_BACKEND", "blocked")  # type: ignore
-
-
-def set_backend(backend: Backend) -> None:
-    global _BACKEND
-    if backend not in ("pallas", "blocked", "naive"):
-        raise ValueError(backend)
-    _BACKEND = backend
-
-
-def get_backend() -> Backend:
-    return _BACKEND
+from . import decode_attention as da
+from . import flash_attention as fa
+from . import ref, ssd_scan
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _pallas(kernel, blocked, batched: tuple, shared: tuple = ()):
+    """``kernel(*batched, *shared)``, made to work where Pallas alone does not.
+
+    * Pallas gives a kernel no reverse-mode rule: the gradient is that of
+      ``blocked``, which computes the same function with the same arguments.
+    * XLA cannot partition a Mosaic kernel: under a plan whose mesh spans
+      several devices, the kernel runs per batch shard inside ``shard_map``
+      (``batched`` split along their leading axis like the plan's
+      activations, ``shared`` replicated).
+    """
+    mesh, act = shard_ctx.get_mesh(), shard_ctx.get_act_spec()
+    if mesh is not None and mesh.size > 1 and act is not None:
+        rows = P(act[0])
+        kernel = jax.shard_map(
+            kernel, mesh=mesh, out_specs=rows, check_vma=False,
+            in_specs=(rows,) * len(batched) + (P(),) * len(shared))
+    fn = jax.custom_vjp(kernel)
+    fn.defvjp(lambda *args: (kernel(*args), args),
+              lambda args, g: jax.vjp(blocked, *args)[1](g))
+    return fn(*batched, *shared)
 
 
 # --------------------------------------------------------------------------
@@ -48,18 +54,16 @@ def _on_tpu() -> bool:
 
 def flash_attention(q, k, v, *, causal=True, window=None, q_offset=0,
                     lengths=None, block_q=512, block_k=512):
-    if _BACKEND == "naive":
-        return ref.attention_naive(q, k, v, causal=causal, window=window,
-                                   q_offset=q_offset, lengths=lengths)
-    if _BACKEND == "pallas":
-        from . import flash_attention as fa
-        return fa.flash_attention(q, k, v, causal=causal, window=window,
-                                  q_offset=q_offset, lengths=lengths,
-                                  block_q=block_q, block_k=block_k,
-                                  interpret=not _on_tpu())
-    return ref.attention_blocked(q, k, v, causal=causal, window=window,
-                                 q_offset=q_offset, lengths=lengths,
-                                 block_q=block_q, block_k=block_k)
+    def attend(impl):
+        return lambda q, k, v, lengths, window: impl(
+            q, k, v, causal=causal, window=window, q_offset=q_offset,
+            lengths=lengths, block_q=block_q, block_k=block_k)
+
+    blocked = attend(ref.attention_blocked)
+    if not _on_tpu():
+        return blocked(q, k, v, lengths, window)
+    return _pallas(attend(fa.flash_attention), blocked, (q, k, v, lengths),
+                   (window,))
 
 
 # --------------------------------------------------------------------------
@@ -68,13 +72,13 @@ def flash_attention(q, k, v, *, causal=True, window=None, q_offset=0,
 
 def decode_attention(q, k_cache, v_cache, lengths, *, window=None,
                      block_k=1024):
-    if _BACKEND == "pallas":
-        from . import decode_attention as da
-        return da.decode_attention(q, k_cache, v_cache, lengths,
-                                   window=window, block_k=block_k,
-                                   interpret=not _on_tpu())
-    return ref.decode_attention_naive(q, k_cache, v_cache, lengths,
-                                      window=window)
+    blocked = lambda q, k, v, lengths, window: ref.decode_attention_naive(
+        q, k, v, lengths, window=window)
+    if not _on_tpu():
+        return blocked(q, k_cache, v_cache, lengths, window)
+    kernel = lambda q, k, v, lengths, window: da.decode_attention(
+        q, k, v, lengths, window=window, block_k=block_k)
+    return _pallas(kernel, blocked, (q, k_cache, v_cache, lengths), (window,))
 
 
 # --------------------------------------------------------------------------
@@ -83,13 +87,14 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window=None,
 
 def ssd(x, dt, A, B, C, D, *, chunk=128, h0=None):
     """Chunked SSD scan (prefill/training)."""
-    if _BACKEND == "naive":
-        return ref.ssd_naive(x, dt, A, B, C, D, h0=h0)
-    if _BACKEND == "pallas":
-        from . import ssd_scan
-        return ssd_scan.ssd(x, dt, A, B, C, D, chunk=chunk, h0=h0,
-                            interpret=not _on_tpu())
-    return ref.ssd_chunked(x, dt, A, B, C, D, chunk=chunk, h0=h0)
+    def scan(impl):
+        return lambda x, dt, B, C, h0, A, D: impl(x, dt, A, B, C, D,
+                                                  chunk=chunk, h0=h0)
+
+    blocked = scan(ref.ssd_chunked)
+    if not _on_tpu():
+        return blocked(x, dt, B, C, h0, A, D)
+    return _pallas(scan(ssd_scan.ssd), blocked, (x, dt, B, C, h0), (A, D))
 
 
 def ssd_decode_step(h, x, dt, A, B, C, D):

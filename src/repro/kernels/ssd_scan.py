@@ -5,88 +5,88 @@ chunk and (b) a linear recurrence across chunk states.  (a) carries ~all the
 FLOPs and maps onto the MXU; (b) is a tiny (nh, hd, n) scan that stays in
 plain XLA (ops wrapper) — forcing it into the kernel would serialise the
 grid for no compute win.  This split is the TPU adaptation of the fused GPU
-kernel in the Mamba-2 release (DESIGN.md §2).
+kernel in the Mamba-2 release.
 
-Kernel, per (batch, chunk) grid cell — all heads processed together so the
-(c, n) B/C panels are loaded once per chunk:
+Kernel, per (batch, chunk, head block) grid cell.  Every operand arrives
+head-major, so the kernel never reshapes or transposes the lane dimension
+(Mosaic cannot lower either); the (c, n) B/C panels keep the same block index
+across a chunk's head blocks, so they are fetched once per chunk:
 
   scores = C · Bᵀ                (c×c, MXU)
-  L      = exp(segsum(dA))       per head (nh, c, c)
+  L      = exp(segsum(dA))       per head (hb, c, c)
   y_diag = (scores ⊙ L_h) · x̄_h  batched over heads (MXU)
-  states = (B ⊙ decay)ᵀ · x̄_h    per-chunk outgoing state (nh, n, hd)
+  states = (B ⊙ decay)ᵀ · x̄_h    per-chunk outgoing state (hb, n, hd)
 
-VMEM at c=128, nh=48, hd=64, n=128: x̄ 1.5 MB + L 3.1 MB + panels < 6 MB.
+VMEM at c=128, hb=8, hd=64, n=128: < 4 MB with double buffering.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from ._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
+
+HEAD_BLOCK = 8
 
 
-def _kernel(xdt_ref, dacs_ref, b_ref, c_ref,
-            ydiag_ref, states_ref,
-            *, nh: int, hd: int, n: int, chunk: int):
-    xdt = xdt_ref[0, 0].astype(jnp.float32)          # (c, nh*hd)
-    dacs = dacs_ref[0, 0].astype(jnp.float32)        # (c, nh) cumsum log-decay
-    B = b_ref[0, 0].astype(jnp.float32)              # (c, n)
-    C = c_ref[0, 0].astype(jnp.float32)              # (c, n)
+def _kernel(xh_ref, dcol_ref, drow_ref, decay_ref, b_ref, c_ref,
+            ydiag_ref, states_ref, *, chunk: int):
+    xh = xh_ref[0, 0]                                # (hb, c, hd)
+    dcol = dcol_ref[0, 0]                            # (hb, c, 1) cumsum log-decay
+    drow = drow_ref[0, 0]                            # (hb, 1, c)
+    decay = decay_ref[0, 0]                          # (hb, c, 1) to chunk end
+    B = b_ref[0, 0]                                  # (c, n)
+    C = c_ref[0, 0]                                  # (c, n)
 
     scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())))  # (c,c)
     # L[h,i,j] = exp(dacs[i,h] - dacs[j,h]) masked to j<=i
-    di = dacs.T[:, :, None]                          # (nh, c, 1)
-    dj = dacs.T[:, None, :]                          # (nh, 1, c)
     ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     tril = (jj <= ii)[None]
-    L = jnp.where(tril, jnp.exp(di - dj), 0.0)       # (nh, c, c)
-    w = scores[None] * L                             # (nh, c, c)
-    xh = xdt.reshape(chunk, nh, hd).transpose(1, 0, 2)   # (nh, c, hd)
-    y = jax.lax.dot_general(w, xh, (((2,), (1,)), ((0,), (0,))))  # (nh,c,hd)
-    ydiag_ref[0, 0] = y.transpose(1, 0, 2).reshape(
-        chunk, nh * hd).astype(ydiag_ref.dtype)
+    L = jnp.where(tril, jnp.exp(dcol - drow), 0.0)   # (hb, c, c)
+    w = scores[None] * L                             # (hb, c, c)
+    y = jax.lax.dot_general(w, xh, (((2,), (1,)), ((0,), (0,))))  # (hb,c,hd)
+    ydiag_ref[0, 0] = y.astype(ydiag_ref.dtype)
 
     # outgoing chunk state: states[h] = Σ_j exp(dacs[-1,h]-dacs[j,h]) B_j x̄_jh
-    decay = jnp.exp(dacs[-1][None, :] - dacs)        # (c, nh)
-    bd = B[:, None, :] * decay[:, :, None]           # (c, nh, n)
-    bd = bd.transpose(1, 2, 0)                       # (nh, n, c)
-    st = jax.lax.dot_general(bd, xh, (((2,), (1,)), ((0,), (0,))))  # (nh,n,hd)
+    bd = B[None] * decay                             # (hb, c, n)
+    st = jax.lax.dot_general(bd, xh, (((1,), (1,)), ((0,), (0,))))  # (hb,n,hd)
     states_ref[0, 0] = st.astype(states_ref.dtype)
 
 
-def ssd_intra_chunk(xdt: jax.Array, dacs: jax.Array, B: jax.Array,
-                    C: jax.Array, *, nh: int, hd: int,
+def ssd_intra_chunk(xh: jax.Array, dacs: jax.Array, B: jax.Array,
+                    C: jax.Array, *,
                     interpret: bool = False) -> tuple[jax.Array, jax.Array]:
-    """xdt: (b, nc, c, nh*hd)  dacs: (b, nc, c, nh)  B/C: (b, nc, c, n).
-    Returns (y_diag (b, nc, c, nh*hd), states (b, nc, nh, n, hd))."""
-    b, nc, c, _ = xdt.shape
+    """xh: (b, nc, nh, c, hd) dt-scaled inputs; dacs: (b, nc, nh, c) cumsum
+    log-decay; B/C: (b, nc, c, n), all float32.
+    Returns (y_diag (b, nc, nh, c, hd), states (b, nc, nh, n, hd))."""
+    b, nc, nh, c, hd = xh.shape
     n = B.shape[-1]
-    kernel = functools.partial(_kernel, nh=nh, hd=hd, n=n, chunk=c)
+    hb = math.gcd(nh, HEAD_BLOCK)
+    dcol = dacs[..., None]                           # (b, nc, nh, c, 1)
+    drow = dacs[..., None, :]                        # (b, nc, nh, 1, c)
+    decay = jnp.exp(dacs[..., -1:] - dacs)[..., None]
+    heads = lambda *tail: pl.BlockSpec(
+        (1, 1, hb) + tail, lambda b, z, h: (b, z, h) + (0,) * len(tail))
+    panel = pl.BlockSpec((1, 1, c, n), lambda b, z, h: (b, z, 0, 0))
     y, st = pl.pallas_call(
-        kernel,
-        grid=(b, nc),
-        in_specs=[
-            pl.BlockSpec((1, 1, c, nh * hd), lambda b, z: (b, z, 0, 0)),
-            pl.BlockSpec((1, 1, c, nh), lambda b, z: (b, z, 0, 0)),
-            pl.BlockSpec((1, 1, c, n), lambda b, z: (b, z, 0, 0)),
-            pl.BlockSpec((1, 1, c, n), lambda b, z: (b, z, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, c, nh * hd), lambda b, z: (b, z, 0, 0)),
-            pl.BlockSpec((1, 1, nh, n, hd), lambda b, z: (b, z, 0, 0, 0)),
-        ],
+        functools.partial(_kernel, chunk=c),
+        grid=(b, nc, nh // hb),
+        in_specs=[heads(c, hd), heads(c, 1), heads(1, c), heads(c, 1),
+                  panel, panel],
+        out_specs=[heads(c, hd), heads(n, hd)],
         out_shape=[
-            jax.ShapeDtypeStruct((b, nc, c, nh * hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, nc, nh, c, hd), jnp.float32),
             jax.ShapeDtypeStruct((b, nc, nh, n, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
-    )(xdt, dacs, B, C)
+    )(xh, dcol, drow, decay, B, C)
     return y, st
 
 
@@ -111,11 +111,12 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     Bf = B.astype(jnp.float32).reshape(b, nc, c, n)
     Cf = C.astype(jnp.float32).reshape(b, nc, c, n)
     dA = dtf * A[None, None, None, :]
-    dA_cs = jnp.cumsum(dA, axis=2)
-    xdt = (xf * dtf[..., None]).reshape(b, nc, c, nh * hd)
+    dA_cs = jnp.cumsum(dA, axis=2)                    # (b, nc, c, nh)
+    xdt = (xf * dtf[..., None]).transpose(0, 1, 3, 2, 4)  # (b, nc, nh, c, hd)
 
-    y_diag, states = ssd_intra_chunk(xdt, dA_cs, Bf, Cf, nh=nh, hd=hd,
-                                     interpret=interpret)
+    y_diag, states = ssd_intra_chunk(xdt, dA_cs.transpose(0, 1, 3, 2), Bf,
+                                     Cf, interpret=interpret)
+    y_diag = y_diag.transpose(0, 1, 3, 2, 4)          # (b, nc, c, nh, hd)
     states = states.transpose(0, 1, 2, 4, 3)          # (b, nc, nh, hd, n)
 
     # inter-chunk recurrence (tiny, stays in XLA)
@@ -132,7 +133,7 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
 
     in_decay = jnp.exp(dA_cs)                         # (b, nc, c, nh)
     y_off = jnp.einsum("bzcn,bzch,bzhpn->bzchp", Cf, in_decay, h_in)
-    y = y_diag.reshape(b, nc, c, nh, hd) + y_off
+    y = y_diag + y_off
     y = y.reshape(b, nc * c, nh, hd)[:, :t]
     y = y + x.astype(jnp.float32)[:, :t] * D[None, None, :, None]
     return y.astype(x.dtype), h_final

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config
-from repro.kernels import ops as kernel_ops
 from repro.launch.mesh import make_production_mesh
 from repro.models import SHAPES, build_model, shape_applicable
 from repro.sharding import ctx as shard_ctx
@@ -269,7 +268,6 @@ def main(argv=None):
                     choices=["data", "model"])
     ap.add_argument("--out", default="experiments/dryrun")
     args = ap.parse_args(argv)
-    kernel_ops.set_backend("blocked")
 
     cells = []
     if args.all:

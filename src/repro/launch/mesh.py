@@ -1,12 +1,21 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-A FUNCTION, not a module-level constant, so importing this module never
+FUNCTIONS, not module-level constants, so importing this module never
 touches jax device state (the dry-run must set XLA_FLAGS before first init).
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """A mesh whose axes are all ``Auto``.  The sharding plans pin layouts
+    with sharding constraints at layer boundaries and leave the rest to XLA's
+    propagation; ``jax.make_mesh`` defaults to ``Explicit`` axes, under which
+    every gather and contraction would have to name its output sharding."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,13 +23,4 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2 pods × 256 chips over ("pod", "data", "model")."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_smoke_mesh(shape=(1, 1), axes=("data", "model")):
-    """Tiny mesh over however many devices exist (CPU tests)."""
-    return jax.make_mesh(shape, axes)
-
-
-def mesh_axis_sizes(mesh) -> dict[str, int]:
-    return dict(zip(mesh.axis_names, mesh.devices.shape))
+    return make_mesh(shape, axes)

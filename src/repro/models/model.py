@@ -117,6 +117,12 @@ class Model:
 
     # ------------------------------------------------------------------ params
     def init(self, key: jax.Array, dtype=jnp.float32) -> dict:
+        """Random parameters in ``dtype``, built by one jitted program: each
+        leaf is written straight into its final (stacked) buffer, so the
+        device never holds per-layer trees or a wider copy."""
+        return jax.jit(self._init, static_argnums=1)(key, dtype)
+
+    def _init(self, key: jax.Array, dtype) -> dict:
         if self.cfg.family == "audio":
             return encdec.init_params(self.cfg, key, dtype)
         if self.cfg.family == "vlm":

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding import ctx as shard_ctx
-from repro.sharding._compat import shard_map
 
 from .config import ArchConfig
 
@@ -227,6 +226,6 @@ def moe_ep_a2a(cfg: ArchConfig, p: dict, x: jax.Array, *,
         w_gate = jnp.repeat(w_gate, replicas, axis=0)
         w_up = jnp.repeat(w_up, replicas, axis=0)
         w_down = jnp.repeat(w_down, replicas, axis=0)
-    fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                   out_specs=P(*act_spec), check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                       out_specs=P(*act_spec), check_vma=False)
     return fn(x, p["router"], w_gate, w_up, w_down)

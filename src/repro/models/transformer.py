@@ -83,9 +83,7 @@ def _stack(template_fn, n: int, key=None):
                        if isinstance(s, jax.ShapeDtypeStruct)
                        else jax.ShapeDtypeStruct((n,) + s.shape, s.dtype)),
             t)
-    keys = jax.random.split(key, n)
-    trees = [template_fn(k) for k in keys]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
+    return jax.vmap(template_fn)(jax.random.split(key, n))
 
 
 def init_params(cfg: ArchConfig, key: jax.Array | None = None,

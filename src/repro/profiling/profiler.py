@@ -11,7 +11,7 @@ Two measurement paths:
 
 * ``profile_kernels`` wall-clock times the actual jax kernels in
   ``repro.kernels`` — the FULL set (prefill flash attention, decode
-  attention, Mamba-2 SSD; blocked/Pallas-interpret lowering on CPU) —
+  attention, Mamba-2 SSD; Pallas on TPU, the blocked jnp path elsewhere) —
   per device, with per-kind shape sweeps (``DEFAULT_KERNEL_SHAPES``).
   ``repro.profiling.calibrate_kernels`` loops it over every visible jax
   device, fits a ``LearnedCostModel`` and persists it through the
@@ -126,7 +126,7 @@ class SyntheticGroundTruth:
 # --------------------------------------------------------------------------
 
 # Default shape sweep for the real-kernel path, per kernel kind.  Small by
-# design (CI runs these under Pallas-interpret on CPU); a hardware
+# design (CI runs these through the blocked jnp path on CPU); a hardware
 # deployment passes its own per-device shapes to ``profile_kernels``.
 DEFAULT_KERNEL_SHAPES: dict[str, tuple[tuple[int, ...], ...]] = {
     # (B, T, H, D) — prefill flash attention

@@ -29,8 +29,6 @@ from repro.models import layers as L
 from repro.models import transformer
 from repro.models.config import ArchConfig
 
-from ._compat import shard_map
-
 
 def stage_params(cfg: ArchConfig, params: dict, n_stages: int) -> dict:
     """Reshape the stacked layer params (L, ...) → (S, L/S, ...)."""
@@ -122,7 +120,7 @@ def pipeline_hidden(cfg: ArchConfig, params_staged: dict, tokens: jax.Array,
         x = outs.reshape(B, T, d)
         return L.apply_norm(cfg, norm_local, x)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), layer_leaves),
                   jax.tree.map(lambda _: P(), embed_p),

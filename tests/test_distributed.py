@@ -32,6 +32,7 @@ def test_sharded_train_step_matches_single_device():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.configs import get_config
+        from repro.launch.mesh import make_mesh
         from repro.models import build_model
         from repro.sharding.plan import ShardingPlan, MeshDesc
         from repro.sharding import specs, ctx as shard_ctx
@@ -55,8 +56,8 @@ def test_sharded_train_step_matches_single_device():
         # single device
         p1, o1, m1 = step(params, optim.init(params), batch)
 
-        # sharded
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        # sharded, on a mesh whose axes leave layouts to XLA's propagation
+        mesh = make_mesh((4, 2), ("data", "model"))
         with mesh:
             p_sh = specs.param_shardings(mesh, params, plan)
             b_sh = specs.batch_shardings(mesh, batch, plan)

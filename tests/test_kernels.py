@@ -1,6 +1,8 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) and blocked-jnp
 implementations vs. the pure-jnp naive oracles in kernels/ref.py."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,7 +12,7 @@ from _hypothesis_compat import given, settings, st
 
 from repro.kernels import decode_attention as da
 from repro.kernels import flash_attention as fa
-from repro.kernels import ref, ssd_scan
+from repro.kernels import ops, ref, ssd_scan
 
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 
@@ -48,6 +50,29 @@ def test_flash_attention_pallas_vs_oracle(shape, dtype, rng):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                atol=TOL[dtype], rtol=TOL[dtype])
+
+
+@pytest.mark.parametrize("shape", SHAPES[1:3])
+def test_flash_attention_tpu_dispatch_gradient(shape, rng, monkeypatch):
+    """On TPU ``ops`` runs the Pallas kernel forward and takes the gradient
+    from the blocked algorithm; both must match the oracle."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    monkeypatch.setattr(fa, "flash_attention",
+                        functools.partial(fa.flash_attention, interpret=True))
+    b, tq, tk, hq, hkv, d, win, caus, bq, bk = shape
+    q, k, v = _mk_qkv(rng, b, tq, tk, hq, hkv, d, jnp.float32)
+    lens = jnp.asarray([tk] + [max(tk * 2 // 3, 1)] * (b - 1))
+    kw = dict(causal=caus, window=win, q_offset=tk - tq, lengths=lens)
+    w = jax.random.normal(jax.random.PRNGKey(1), (b, tq, hq, d))
+    loss = lambda f: lambda q, k, v: jnp.sum(f(q, k, v) * w)
+    got = jax.value_and_grad(loss(functools.partial(
+        ops.flash_attention, block_q=bq, block_k=bk, **kw)),
+        argnums=(0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(loss(functools.partial(
+        ref.attention_naive, **kw)), argnums=(0, 1, 2))(q, k, v)
+    for g, w_ in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w_),
+                                   atol=1e-4, rtol=1e-4)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -345,3 +345,37 @@ def test_fleet_epoch_resizes_elastic_world():
     members = [e for e in rec.events if e.name == "fleet.membership"]
     assert [(e.value, e.epoch) for e in members] == [(1.0, 1), (2.0, 2)]
     assert len([e for e in rec.events if e.name == "elastic.replan"]) == 2
+
+
+def test_serve_refuses_to_run_off_the_chip():
+    """The serve entry point never falls back to the CPU."""
+    from repro.launch import serve
+    with pytest.raises(SystemExit, match="platform 'cpu'"):
+        serve.main([])
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and the code sets no other directory;
+    without it the cache sits at the fixed <repo>/.jax_cache."""
+    from repro.launch import serve
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert serve.place_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = serve.place_compile_cache()
+        assert path == str(serve.REPO / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_seeded_prompts_are_reproducible_and_in_range():
+    from repro.launch.serve import seeded_prompts
+    a = seeded_prompts(256000, 8, (16, 512), seed=0)
+    b = seeded_prompts(256000, 8, (16, 512), seed=0)
+    assert [p.tolist() for p in a] == [p.tolist() for p in b]
+    assert all(16 <= len(p) <= 512 and p.dtype == np.int32 for p in a)
+    assert all(int(p.max()) < 256000 for p in a)
