@@ -18,7 +18,6 @@ Runs identically on a CPU test mesh (tiny configs) and the production mesh.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -32,6 +31,7 @@ from repro.core.fingerprint import dag_fingerprint
 from repro.core.objective import METRICS
 from repro.core.scheduler import State
 from repro.models.model import Model
+from repro.telemetry import active, host_span
 
 
 @dataclasses.dataclass
@@ -46,6 +46,8 @@ class Request:
     # which tenant (ModelDAG) this request belongs to — resolved against
     # the shared PlanCache; None when the engine serves without a cache
     dag: Any = None
+    # host time (time.perf_counter) at submit
+    submitted: float = 0.0
     # filled during serving
     slot: int | None = None
     generated: list[int] = dataclasses.field(default_factory=list)
@@ -90,7 +92,17 @@ class ServingEngine:
     ``telemetry`` (a ``repro.telemetry.TelemetryRecorder``) records every
     submit's per-tenant cache resolution (hit | miss | none) and every
     EXPLORE re-entry (drift or membership epoch) as structured counters —
-    see docs/observability.md."""
+    see docs/observability.md.
+
+    Every ``step`` marks its parts as spans (``repro.telemetry.host_span``):
+    ``engine.step`` holds ``engine.admit`` (with one ``engine.prefill``,
+    ``engine.write_slot`` and ``engine.first_token`` per admitted request,
+    each carrying ``request`` and ``plen``), ``engine.decode`` (with
+    ``engine.decode_inputs``, ``engine.decode_dispatch`` and
+    ``engine.decode_wait``) and ``engine.sample``.  They land in an open
+    ``jax.profiler`` trace beside the device's work, where the jitted
+    programs run as ``jit_engine_prefill`` and ``jit_engine_decode``; with
+    a recorder wired they are recorded there too."""
 
     def __init__(self, model: Model, params: dict, *, max_batch: int = 4,
                  max_len: int = 128, plan=None, donate: bool = True,
@@ -103,8 +115,7 @@ class ServingEngine:
         self.plan = plan
         self.feedback = feedback
         self.on_replan = on_replan
-        from repro.telemetry import active as _tel_active
-        self.telemetry = _tel_active(telemetry)
+        self.telemetry = active(telemetry)
         if plan_cache is None and default_dag is not None:
             raise ValueError(
                 "default_dag names the tenant submits resolve against a "
@@ -125,11 +136,14 @@ class ServingEngine:
         self.completed: dict[int, Request] = {}
         self._next_id = 0
         self.state = State.ANALYZE
-        self.trace: list[State] = []
 
-        self._decode = jax.jit(
-            lambda p, c, b: model.apply_decode(p, c, b),
-            donate_argnums=(1,) if donate else ())
+        # a named function, so that the program runs under a stable name
+        # (jit_engine_decode; jit_engine_prefill below) in a profiler trace
+        def engine_decode(params, cache, batch):
+            return model.apply_decode(params, cache, batch)
+
+        self._decode = jax.jit(engine_decode,
+                               donate_argnums=(1,) if donate else ())
         self._prefill_cache: dict[int, Callable] = {}
 
     # ------------------------------------------------------------------ API
@@ -165,11 +179,9 @@ class ServingEngine:
             # the resolve context roots this submit's trace subtree: the
             # cache's hit/miss counters and any frontier-pass span it
             # triggers auto-parent under it
-            with (self.telemetry.trace(
-                      "engine.resolve", tenant=dag.name, request=rid,
-                      objective=objective, wall=True)
-                  if self.telemetry is not None
-                  else contextlib.nullcontext()):
+            with host_span("engine.resolve", self.telemetry,
+                           tenant=dag.name, request=rid,
+                           objective=objective):
                 self.plan = self.plan_cache.get(dag, objective=objective,
                                                 delta=delta)
                 fp = dag_fingerprint(dag)
@@ -189,7 +201,8 @@ class ServingEngine:
                                    objective=objective, resolved="none")
         self.queue.append(Request(rid, np.asarray(prompt, np.int32),
                                   max_new_tokens, eos_id,
-                                  objective=objective, dag=dag))
+                                  objective=objective, dag=dag,
+                                  submitted=time.perf_counter()))
         return rid
 
     def active(self) -> int:
@@ -263,16 +276,12 @@ class ServingEngine:
         ``epoch`` (the :class:`~repro.fleet.MembershipEpoch`) is accepted
         and ignored so the callback wires directly."""
         self.state = State.EXPLORE
-        self.trace.append(self.state)
         self.replans += 1
         # one trace subtree per EXPLORE re-entry: the replan counter and
         # every per-tenant resolution (warm hit or frontier pass) parent
         # under it
-        with (self.telemetry.trace(
-                  "engine.replan_pass", reason="epoch",
-                  epoch=getattr(epoch, "epoch", None), wall=True)
-              if self.telemetry is not None
-              else contextlib.nullcontext()):
+        with host_span("engine.replan_pass", self.telemetry,
+                       reason="epoch", epoch=getattr(epoch, "epoch", None)):
             if self.telemetry is not None:
                 self.telemetry.counter(
                     "engine.replan", reason="epoch",
@@ -293,49 +302,58 @@ class ServingEngine:
     # ----------------------------------------------------------------- admit
     def _prefill_fn(self, plen: int) -> Callable:
         if plen not in self._prefill_cache:
-            self._prefill_cache[plen] = jax.jit(
-                lambda p, b: self.model.apply_prefill(p, b))
+            model = self.model
+
+            def engine_prefill(params, batch):
+                return model.apply_prefill(params, batch)
+
+            self._prefill_cache[plen] = jax.jit(engine_prefill)
         return self._prefill_cache[plen]
 
     def _admit(self) -> None:
         self.state = State.ANALYZE
-        self.trace.append(self.state)
-        for slot in range(self.max_batch):
-            if self.slot_req[slot] is not None or not self.queue:
-                continue
-            req = self.queue.popleft()
-            plen = len(req.prompt)
-            batch = {"tokens": jnp.asarray(req.prompt[None, :])}
-            if self.model.cfg.family == "audio":
-                batch["frames"] = jnp.zeros(
-                    (1, max(plen // 2, 1), self.model.cfg.d_model),
-                    jnp.bfloat16)
-            if self.model.cfg.family == "vlm":
-                batch["vision"] = jnp.zeros(
-                    (1, self.model.cfg.n_vision_tokens,
-                     self.model.cfg.d_model), jnp.bfloat16)
-            batch["lengths"] = jnp.asarray([plen], jnp.int32)
-            logits, pcache = self._prefill_fn(plen)(self.params, batch)
-            self._write_slot(slot, pcache, plen)
-            first = int(jnp.argmax(logits[0, -1]))
-            req.slot = slot
-            req.generated.append(first)
-            self.slot_req[slot] = req
-            self.lengths[slot] = plen + 1
-            self._append_token(slot, first, plen)
+        tel = self.telemetry
+        with host_span("engine.admit", tel):
+            for slot in range(self.max_batch):
+                if self.slot_req[slot] is not None or not self.queue:
+                    continue
+                req = self.queue.popleft()
+                rid, plen = req.request_id, len(req.prompt)
+                # a prompt length's first prefill makes its program
+                new = plen not in self._prefill_cache
+                queued_ms = (time.perf_counter() - req.submitted) * 1e3
+                with host_span("engine.prefill", tel,
+                               wall_attrs={"queued_ms": queued_ms},
+                               request=rid, plen=plen, new_program=int(new)):
+                    logits, pcache = self._prefill_fn(plen)(
+                        self.params, self._prefill_batch(req))
+                with host_span("engine.write_slot", tel, request=rid,
+                               plen=plen):
+                    self._write_slot(slot, pcache, plen)
+                with host_span("engine.first_token", tel, request=rid,
+                               plen=plen):
+                    first = int(jnp.argmax(logits[0, -1]))
+                req.slot = slot
+                req.generated.append(first)
+                self.slot_req[slot] = req
+                self.lengths[slot] = plen + 1
+
+    def _prefill_batch(self, req: Request) -> dict:
+        plen = len(req.prompt)
+        batch = {"tokens": jnp.asarray(req.prompt[None, :])}
+        if self.model.cfg.family == "audio":
+            batch["frames"] = jnp.zeros(
+                (1, max(plen // 2, 1), self.model.cfg.d_model), jnp.bfloat16)
+        if self.model.cfg.family == "vlm":
+            batch["vision"] = jnp.zeros(
+                (1, self.model.cfg.n_vision_tokens, self.model.cfg.d_model),
+                jnp.bfloat16)
+        batch["lengths"] = jnp.asarray([plen], jnp.int32)
+        return batch
 
     def _write_slot(self, slot: int, pcache: dict, plen: int) -> None:
         """Copy a (L, 1, P, ...) prefill cache into slot ``slot`` of the
         engine cache (padded to max_len)."""
-        def write(dst, src):
-            if dst.ndim >= 3 and src.shape[-1] == dst.shape[-1] \
-                    and dst.shape[-3] == self.max_len:
-                # (..., B, S, H, D) positional cache
-                return dst.at[..., slot, :src.shape[-3], :, :].set(
-                    src[..., 0, :, :, :])
-            # recurrent state: (..., B, ...) — copy the batch slice
-            return dst.at[..., slot:slot + 1, :, :].set(src) \
-                if False else dst
         new = {}
         for k in self.cache:
             dst, src = self.cache[k], pcache[k]
@@ -351,57 +369,69 @@ class ServingEngine:
                 new[k] = dst
         self.cache = new
 
-    def _append_token(self, slot: int, token: int, pos: int) -> None:
-        pass  # token history kept host-side in Request.generated
-
     # ---------------------------------------------------------------- decode
     def step(self) -> None:
-        self._admit()
-        if self.active() == 0:
+        tel = self.telemetry
+        with host_span("engine.step", tel):
+            self._admit()
+            if self.active() == 0:
+                return
+            self.state = State.EXECUTE
+            with host_span("engine.decode", tel, active=self.active()):
+                with host_span("engine.decode_inputs", tel):
+                    tokens = np.zeros((self.max_batch, 1), np.int32)
+                    for s, req in enumerate(self.slot_req):
+                        if req is not None:
+                            tokens[s, 0] = req.generated[-1]
+                    batch = {"tokens": jnp.asarray(tokens),
+                             "lengths": jnp.asarray(
+                                 np.maximum(self.lengths, 1))}
+                t0 = time.perf_counter()
+                with host_span("engine.decode_dispatch", tel):
+                    logits, self.cache = self._decode(self.params,
+                                                      self.cache, batch)
+                with host_span("engine.decode_wait", tel):
+                    jax.block_until_ready(logits)
+                step_s = time.perf_counter() - t0
+            self._decode_steps += 1
+            if self.feedback is not None and self._decode_steps > 1:
+                # step 1 pays jit compilation — not a hardware signal
+                self._observe(step_s)
+            with host_span("engine.sample", tel):
+                self._sample(logits)
+
+    def _observe(self, step_s: float) -> None:
+        """Report one decode step's latency to the feedback loop; on drift,
+        re-enter EXPLORE."""
+        # work = decoded tokens this step (batch-occupancy proxy for
+        # FLOPs; the loop's regressor absorbs the per-token constant)
+        drifted = self.feedback.observe(
+            "engine/decode", "decode", float(self.active()), 0.0, step_s)
+        if not drifted:
             return
-        self.state = State.EXECUTE
-        self.trace.append(self.state)
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        for s, req in enumerate(self.slot_req):
-            if req is not None:
-                tokens[s, 0] = req.generated[-1]
-        batch = {"tokens": jnp.asarray(tokens),
-                 "lengths": jnp.asarray(np.maximum(self.lengths, 1))}
-        t0 = time.perf_counter()
-        logits, self.cache = self._decode(self.params, self.cache, batch)
-        jax.block_until_ready(logits)
-        step_s = time.perf_counter() - t0
-        self._decode_steps += 1
-        if self.feedback is not None and self._decode_steps > 1:
-            # step 1 pays jit compilation — not a hardware signal
-            # work = decoded tokens this step (batch-occupancy proxy for
-            # FLOPs; the loop's regressor absorbs the per-token constant)
-            drifted = self.feedback.observe(
-                "engine/decode", "decode", float(self.active()), 0.0, step_s)
-            if drifted:
-                self.state = State.EXPLORE
-                self.trace.append(self.state)
-                self.replans += 1
-                with (self.telemetry.trace("engine.replan_pass",
-                                           reason="drift", wall=True)
-                      if self.telemetry is not None
-                      else contextlib.nullcontext()):
-                    if self.telemetry is not None:
-                        self.telemetry.counter(
-                            "engine.replan", reason="drift",
-                            tenants=len(self._tenant_traffic()))
-                    if self.plan_cache is not None:
-                        # the drift already bumped the calibration version
-                        # (via version_source or this on_drift); re-plan
-                        # exactly once *per in-flight tenant* — each
-                        # tenant's first post-bump lookup is its single
-                        # frontier pass — at the objective that tenant's
-                        # traffic wants and the delta its front was keyed
-                        # under
-                        self.plan_cache.on_drift()
-                        self._replan_in_flight_tenants()
-                    if self.on_replan is not None:
-                        self.on_replan()
+        self.state = State.EXPLORE
+        self.replans += 1
+        with host_span("engine.replan_pass", self.telemetry,
+                       reason="drift"):
+            if self.telemetry is not None:
+                self.telemetry.counter(
+                    "engine.replan", reason="drift",
+                    tenants=len(self._tenant_traffic()))
+            if self.plan_cache is not None:
+                # the drift already bumped the calibration version (via
+                # version_source or this on_drift); re-plan exactly once
+                # *per in-flight tenant* — each tenant's first post-bump
+                # lookup is its single frontier pass — at the objective
+                # that tenant's traffic wants and the delta its front was
+                # keyed under
+                self.plan_cache.on_drift()
+                self._replan_in_flight_tenants()
+            if self.on_replan is not None:
+                self.on_replan()
+
+    def _sample(self, logits) -> None:
+        """Greedy next tokens for every slot in use; retire the requests
+        that are done."""
         nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
         for s, req in enumerate(self.slot_req):
             if req is None:

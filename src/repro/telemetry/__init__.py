@@ -6,7 +6,7 @@ EdgeSimulator` (request/attempt spans, retries, migrations, SLO,
 joules), the :class:`~repro.serving.plan_cache.PlanCache` (per-tenant
 hits/misses/evictions, DP frontier-pass spans), the
 :class:`~repro.serving.engine.ServingEngine` (per-tenant cache
-resolutions, EXPLORE re-entries), the :class:`~repro.fleet.
+resolutions, EXPLORE re-entries, the spans of every step), the :class:`~repro.fleet.
 FleetController` (membership gauges, leader fail-overs), the
 :class:`~repro.profiling.FeedbackLoop` (drift magnitude gauges), the
 :class:`~repro.runtime.elastic.ElasticController` (world-size gauges),
@@ -23,11 +23,13 @@ simulator's ``SimReport`` aggregates *exactly* from the log.
 Determinism and overhead are contracts, not hopes: seeded replays are
 byte-identical modulo the designated wall-clock fields, and a disabled
 recorder normalizes to no recorder at all (see :func:`active`), gated at
-≤2 % in fig7.  See docs/observability.md.
+≤2 % in fig7.  :func:`host_span` also puts a span in the profiler's own
+trace, beside the device's work.  See docs/observability.md.
 """
 
 from .events import KINDS, WALL_FIELDS, TelemetryEvent  # noqa: F401
-from .recorder import SpanHandle, TelemetryRecorder, active  # noqa: F401
+from .recorder import (SpanHandle, TelemetryRecorder, active,  # noqa: F401
+                       host_span)
 from .report import run_summary, sim_aggregates  # noqa: F401
 from .store import RunStore  # noqa: F401
 from .trace import (SpanNode, critical_path,  # noqa: F401

@@ -35,6 +35,11 @@ Lifecycle::
 
 ``flush_every=N`` bounds the in-memory buffer for long runs; ``close``
 always flushes the tail and stamps the manifest with per-kind counts.
+
+:func:`host_span` puts a span on two clocks at once: the profiler's (a
+``jax.profiler.TraceAnnotation``, which lands in the same trace as the
+device's operations when a profiler session is open and costs about a
+microsecond when none is) and, where a recorder is wired, the recorder's.
 """
 
 from __future__ import annotations
@@ -42,6 +47,8 @@ from __future__ import annotations
 import contextlib
 import time
 from typing import Iterator
+
+from jax.profiler import TraceAnnotation
 
 from .events import TelemetryEvent
 
@@ -96,6 +103,31 @@ def active(telemetry: "TelemetryRecorder | None"
     if telemetry is None or not telemetry.enabled:
         return None
     return telemetry
+
+
+def host_span(name: str, recorder: "TelemetryRecorder | None" = None,
+              wall_attrs: dict | None = None, **attrs):
+    """A context manager that marks a block of host work as span ``name``.
+
+    It always opens ``jax.profiler.TraceAnnotation(name, **attrs)``, so the
+    span and its attributes (as the event's stats) sit in the profiler's
+    trace beside the device operations the block launched.  With a
+    ``recorder`` it also opens ``recorder.trace(name, wall=True, **attrs)``:
+    the span is then wall-clocked, parents the events emitted inside it,
+    and is a child of the recorder's innermost open span; the context then
+    yields the recorder's :class:`SpanHandle`.  ``wall_attrs`` are readings
+    of the host's clock: they go to the profiler's annotation alone, as the
+    recorder keeps wall-clock facts in its wall fields only."""
+    ann = TraceAnnotation(name, **attrs, **(wall_attrs or {}))
+    if recorder is None:
+        return ann
+    return _both(ann, recorder.trace(name, wall=True, **attrs))
+
+
+@contextlib.contextmanager
+def _both(ann, ctx) -> Iterator[SpanHandle]:
+    with ann, ctx as handle:
+        yield handle
 
 
 class TelemetryRecorder:

@@ -9,6 +9,7 @@ import pytest
 from repro.configs import get_config
 from repro.models import build_model
 from repro.serving.engine import ServingEngine
+from repro.telemetry import TelemetryRecorder
 
 
 @pytest.fixture(scope="module")
@@ -17,6 +18,11 @@ def small_lm():
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(3))
     return cfg, model, params
+
+
+def _replans_recorded(rec: TelemetryRecorder) -> int:
+    """EXPLORE re-entries the engine reported to its recorder."""
+    return sum(e.name == "engine.replan" for e in rec.events)
 
 
 def _reference_greedy(model, params, prompt, n_new):
@@ -80,7 +86,6 @@ def test_engine_feedback_reenters_explore_on_drift(small_lm):
     """Closed loop at serving time: a cost model that wildly underestimates
     decode latency drifts immediately; the engine re-enters EXPLORE (Fig. 4)
     and fires the re-plan hook, and the refitted model then tracks reality."""
-    from repro.core.scheduler import State
     from repro.profiling import FeedbackLoop, LearnedCostModel
 
     cfg, model, params = small_lm
@@ -91,13 +96,14 @@ def test_engine_feedback_reenters_explore_on_drift(small_lm):
     replans = []
     fb = FeedbackLoop(beliefs, threshold=0.75,
                       on_drift=lambda: replans.append(fb.observations))
+    rec = TelemetryRecorder("engine")
     eng = ServingEngine(model, params, max_batch=1, max_len=64,
-                        feedback=fb, on_replan=lambda: None)
+                        feedback=fb, on_replan=lambda: None, telemetry=rec)
     rid = eng.submit(np.asarray([5, 9, 2], np.int32), max_new_tokens=40)
     done = eng.run_until_done()
     assert done[rid].done
     assert eng.replans >= 1 and replans
-    assert State.EXPLORE in eng.trace
+    assert _replans_recorded(rec) == eng.replans
     # after the hard refit the model's belief is in the measured ballpark
     pred = beliefs.predict("engine/decode", "decode", 1.0, 0.0)
     assert pred is not None and pred > 1e-7
@@ -168,7 +174,6 @@ def test_engine_drift_triggers_exactly_one_cache_replan(small_lm):
     """Drift while serving: the calibration version bumps, the cached
     frontier invalidates, and the engine re-enters EXPLORE with exactly one
     frontier re-plan at the dominant objective."""
-    from repro.core.scheduler import State
     from repro.profiling import FeedbackLoop, LearnedCostModel
 
     cfg, model, params = small_lm
@@ -177,13 +182,15 @@ def test_engine_drift_triggers_exactly_one_cache_replan(small_lm):
     beliefs.fit_entry("engine/decode", "decode",
                       [(1.0, 0.0, 1e-9), (2.0, 0.0, 2e-9)])
     fb = FeedbackLoop(beliefs, threshold=0.75)
+    rec = TelemetryRecorder("engine")
     eng = ServingEngine(model, params, max_batch=1, max_len=64,
-                        feedback=fb, plan_cache=cache, default_dag=dag)
+                        feedback=fb, plan_cache=cache, default_dag=dag,
+                        telemetry=rec)
     rid = eng.submit(np.asarray([5, 9, 2], np.int32), max_new_tokens=40,
                      objective="energy")
     done = eng.run_until_done()
     assert done[rid].done
-    assert eng.replans >= 1 and State.EXPLORE in eng.trace
+    assert eng.replans >= 1 and _replans_recorded(rec) == eng.replans
     # one miss to warm the cache + one EXPLORE re-plan per drift event
     assert cache.misses == 1 + eng.replans
     assert cache.invalidations == eng.replans
@@ -197,7 +204,6 @@ def test_engine_drift_replans_each_tenant_exactly_once(small_lm):
     import dataclasses
 
     from repro.core import dag_fingerprint
-    from repro.core.scheduler import State
     from repro.profiling import FeedbackLoop, LearnedCostModel
 
     cfg, model, params = small_lm
@@ -208,15 +214,16 @@ def test_engine_drift_replans_each_tenant_exactly_once(small_lm):
     beliefs.fit_entry("engine/decode", "decode",
                       [(1.0, 0.0, 1e-9), (2.0, 0.0, 2e-9)])
     fb = FeedbackLoop(beliefs, threshold=0.75)
+    rec = TelemetryRecorder("engine")
     eng = ServingEngine(model, params, max_batch=2, max_len=64,
-                        feedback=fb, plan_cache=cache)
+                        feedback=fb, plan_cache=cache, telemetry=rec)
     ra = eng.submit(np.asarray([5, 9, 2], np.int32), max_new_tokens=40,
                     objective="energy", dag=dag_a)
     rb = eng.submit(np.asarray([1, 4], np.int32), max_new_tokens=40,
                     objective="latency", dag=dag_b)
     done = eng.run_until_done()
     assert done[ra].done and done[rb].done
-    assert eng.replans >= 1 and State.EXPLORE in eng.trace
+    assert eng.replans >= 1 and _replans_recorded(rec) == eng.replans
     # one miss per tenant to warm the cache + one re-plan per tenant per
     # drift event — never more
     assert cache.misses == 2 + 2 * eng.replans
@@ -234,7 +241,6 @@ def test_engine_membership_epoch_replans_each_tenant_once(small_lm):
     tenant — a single frontier pass for the never-seen membership, and
     zero DP work when the departed node returns (the membership key flips
     back to its original value)."""
-    from repro.core.scheduler import State
     from repro.fleet import ChurnTrace, FleetController
 
     cfg, model, params = small_lm
@@ -242,18 +248,19 @@ def test_engine_membership_epoch_replans_each_tenant_once(small_lm):
     fleet = FleetController(cache.cluster, ChurnTrace.scripted(
         [(1.0, "tx2", "leave"), (2.0, "tx2", "join")]))
     cache.membership_source = fleet
+    rec = TelemetryRecorder("engine")
     eng = ServingEngine(model, params, max_batch=2, max_len=32,
-                        plan_cache=cache, default_dag=dag)
+                        plan_cache=cache, default_dag=dag, telemetry=rec)
     fleet.on_epoch = lambda ep: eng.on_membership_change(ep)
     eng.submit(np.asarray([1, 2], np.int32), max_new_tokens=4)
     assert cache.misses == 1                 # cold pass, full membership
     fleet.advance(1.5)                       # tx2 leaves → epoch 1
-    assert eng.replans == 1 and State.EXPLORE in eng.trace
+    assert eng.replans == 1 and _replans_recorded(rec) == 1
     assert cache.misses == 2                 # one pass for the new mask
     assert all(a.node.name != "tx2"
                for a in eng.plan.global_plan.assignments)
     fleet.advance(2.5)                       # tx2 returns → epoch 2
-    assert eng.replans == 2
+    assert eng.replans == 2 and _replans_recorded(rec) == 2
     assert cache.misses == 2                 # warm return: zero DP work
     assert cache.hits >= 1
     done = eng.run_until_done()
@@ -379,3 +386,125 @@ def test_seeded_prompts_are_reproducible_and_in_range():
     assert [p.tolist() for p in a] == [p.tolist() for p in b]
     assert all(16 <= len(p) <= 512 and p.dtype == np.int32 for p in a)
     assert all(int(p.max()) < 256000 for p in a)
+
+
+# ----------------------------------------------------------------- spans
+# prompt lengths 3, 4, 3, 5: the second prompt of length 3 reuses the
+# prefill program the first one made
+SPAN_PROMPTS = ([1, 2, 3], [9, 8, 7, 6], [4, 4, 1], [11, 3, 5, 2, 1])
+
+
+def _serve_steps(eng) -> int:
+    for p in SPAN_PROMPTS:
+        eng.submit(np.asarray(p, np.int32), max_new_tokens=3)
+    steps = 0
+    while eng.queue or eng.active():
+        eng.step()
+        steps += 1
+    return steps
+
+
+def _profiled_spans(logdir, run):
+    """Runs ``run`` under the profiler; returns its ``engine.*`` host
+    events as (name, start_ns, end_ns, stats), in order of start."""
+    import glob
+    import os
+
+    jax.profiler.start_trace(str(logdir))
+    try:
+        run()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(logdir), "**", "*.xplane.pb"),
+                      recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    return sorted(((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats))
+                   for plane in data.planes if plane.name.startswith("/host:")
+                   for line in plane.lines for e in line.events
+                   if e.name.startswith("engine.")),
+                  key=lambda e: e[1])
+
+
+def _inside(spans, outer, name):
+    return [s for s in spans if s[0] == name
+            and outer[1] <= s[1] and s[2] <= outer[2]]
+
+
+def test_engine_spans_land_in_the_profiler_trace(small_lm, tmp_path):
+    """Each step's parts are spans in the profiler's own trace: a step holds
+    its admission, decode and sample in that order; every admitted request
+    has one prefill, slot write and first token, with its id and length;
+    ``new_program`` marks only a prompt length's first prefill."""
+    cfg, model, params = small_lm
+    eng = ServingEngine(model, params, max_batch=2, max_len=32)
+    steps = []
+    spans = _profiled_spans(tmp_path,
+                            lambda: steps.append(_serve_steps(eng)))
+    step_spans = [s for s in spans if s[0] == "engine.step"]
+    assert len(step_spans) == steps[0] > 0
+    for st in step_spans:
+        parts = [_inside(spans, st, n) for n in
+                 ("engine.admit", "engine.decode", "engine.sample")]
+        assert [len(p) for p in parts] == [1, 1, 1]
+        admit, decode, sample = (p[0] for p in parts)
+        assert admit[2] <= decode[1] and decode[2] <= sample[1]
+        for child in ("engine.decode_inputs", "engine.decode_dispatch",
+                      "engine.decode_wait"):
+            assert len(_inside(spans, decode, child)) == 1
+        assert decode[3]["active"] >= 1
+    for name in ("engine.prefill", "engine.write_slot", "engine.first_token"):
+        got = sorted((s[3]["request"], s[3]["plen"])
+                     for s in spans if s[0] == name)
+        assert got == [(i, len(p)) for i, p in enumerate(SPAN_PROMPTS)], name
+        for s in spans:
+            if s[0] == name:
+                st, = [t for t in step_spans if t[1] <= s[1] and s[2] <= t[2]]
+                assert len(_inside(spans, st, "engine.admit")) == 1
+    prefills = [s[3] for s in spans if s[0] == "engine.prefill"]
+    assert [p["new_program"] for p in prefills] == [1, 1, 0, 1]
+    assert all(p["queued_ms"] >= 0 for p in prefills)
+
+
+def test_engine_spans_rebuild_one_tree_per_step(small_lm):
+    """With a recorder wired, the same spans nest into one tree per step,
+    a request's spans sharing its id."""
+    from repro.telemetry import span_trees
+
+    cfg, model, params = small_lm
+    rec = TelemetryRecorder("engine")
+    eng = ServingEngine(model, params, max_batch=2, max_len=32,
+                        telemetry=rec)
+    steps = _serve_steps(eng)
+    roots = span_trees(rec.events)
+    assert [r.name for r in roots] == ["engine.step"] * steps
+    admitted = []
+    for root in roots:
+        assert [c.name for c in root.children] == [
+            "engine.admit", "engine.decode", "engine.sample"]
+        admit, decode, _ = root.children
+        assert [c.name for c in decode.children] == [
+            "engine.decode_inputs", "engine.decode_dispatch",
+            "engine.decode_wait"]
+        names = [c.name for c in admit.children]
+        assert names == ["engine.prefill", "engine.write_slot",
+                         "engine.first_token"] * (len(names) // 3)
+        for i in range(0, len(names), 3):
+            ids = {c.event.attrs["request"]
+                   for c in admit.children[i:i + 3]}
+            assert len(ids) == 1
+            admitted += ids
+        assert all(n.event.wall_s is not None for n in root.walk())
+    assert admitted == list(range(len(SPAN_PROMPTS)))
+
+
+def test_engine_without_recorder_records_nothing(small_lm):
+    """No recorder (or a disabled one): the engine keeps no record of its
+    steps on the host."""
+    cfg, model, params = small_lm
+    rec = TelemetryRecorder("engine", enabled=False)
+    eng = ServingEngine(model, params, max_batch=2, max_len=32,
+                        telemetry=rec)
+    assert _serve_steps(eng) > 0 and len(eng.completed) == len(SPAN_PROMPTS)
+    assert eng.telemetry is None and rec.events == []
+    assert not hasattr(eng, "trace")

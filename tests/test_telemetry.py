@@ -65,6 +65,30 @@ def test_recorder_seq_clock_and_counts():
     assert timed.wall_s is not None and timed.wall_s >= 0.0
 
 
+def test_host_span_records_nested_spans_with_their_attributes():
+    """host_span opens a profiler annotation always and, with a recorder,
+    the recorder's wall-clocked trace context: nesting and attributes land
+    in the log, and without a recorder nothing does."""
+    from repro.telemetry import host_span, span_trees
+
+    rec = TelemetryRecorder("spans")
+    with host_span("outer", rec, wall_attrs={"queued_ms": 2.5}, tenant="t1",
+                   request=1) as h:
+        assert h.span_id is not None
+        with host_span("inner", rec, plen=4):
+            rec.counter("inner.hit")
+    with host_span("alone", None, request=2):
+        pass
+    root, = span_trees(rec.events)
+    assert root.name == "outer" and root.event.tenant == "t1"
+    assert root.event.attrs == {"request": 1}      # no wall-clock reading
+    inner, = root.children
+    assert inner.name == "inner" and inner.event.attrs == {"plen": 4}
+    assert [e.name for e in inner.events] == ["inner.hit"]
+    assert all(n.event.wall_s is not None for n in root.walk())
+    assert len(rec.events) == 3
+
+
 def test_disabled_recorder_normalizes_away_and_emits_nothing():
     off = TelemetryRecorder("off", enabled=False)
     assert active(off) is None and active(None) is None
