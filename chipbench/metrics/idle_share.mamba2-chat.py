@@ -1,0 +1,2 @@
+"""Percent of the traced window with no operation on the device."""
+from chipbench.readers import idle_share as read  # noqa: F401

@@ -86,16 +86,22 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window=None,
 # --------------------------------------------------------------------------
 
 def ssd(x, dt, A, B, C, D, *, chunk=128, h0=None):
-    """Chunked SSD scan (prefill/training)."""
+    """Chunked SSD scan (prefill/training), named ``ssd_scan`` in the
+    program's operations."""
     def scan(impl):
         return lambda x, dt, B, C, h0, A, D: impl(x, dt, A, B, C, D,
                                                   chunk=chunk, h0=h0)
 
     blocked = scan(ref.ssd_chunked)
-    if not _on_tpu():
-        return blocked(x, dt, B, C, h0, A, D)
-    return _pallas(scan(ssd_scan.ssd), blocked, (x, dt, B, C, h0), (A, D))
+    with jax.named_scope("ssd_scan"):
+        if not _on_tpu():
+            return blocked(x, dt, B, C, h0, A, D)
+        return _pallas(scan(ssd_scan.ssd), blocked, (x, dt, B, C, h0),
+                       (A, D))
 
 
 def ssd_decode_step(h, x, dt, A, B, C, D):
-    return ref.ssd_decode_step(h, x, dt, A, B, C, D)
+    """One token's state update and readout, named ``ssd_decode_step`` in
+    the program's operations."""
+    with jax.named_scope("ssd_decode_step"):
+        return ref.ssd_decode_step(h, x, dt, A, B, C, D)

@@ -86,6 +86,7 @@ def ssd_intra_chunk(xh: jax.Array, dacs: jax.Array, B: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
+        name="ssd_scan",
     )(xh, dcol, drow, decay, B, C)
     return y, st
 

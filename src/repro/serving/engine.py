@@ -33,6 +33,12 @@ from repro.core.scheduler import State
 from repro.models.model import Model
 from repro.telemetry import active, host_span
 
+#: cache entries held per position of a slot (attention's keys and values)
+KV_KEYS = ("k", "v", "xk", "xv")
+#: cache entries held whole per slot (a state-space layer's recurrent state
+#: and its convolution's context)
+STATE_KEYS = ("h", "conv")
+
 
 @dataclasses.dataclass
 class Request:
@@ -97,7 +103,10 @@ class ServingEngine:
     Every ``step`` marks its parts as spans (``repro.telemetry.host_span``):
     ``engine.step`` holds ``engine.admit`` (with one ``engine.prefill``,
     ``engine.write_slot`` and ``engine.first_token`` per admitted request,
-    each carrying ``request`` and ``plen``), ``engine.decode`` (with
+    each carrying ``request`` and ``plen``; the slot write also carries the
+    bytes it stores, ``state_bytes`` of recurrent state and ``kv_bytes`` of
+    keys and values), ``engine.decode`` (carrying ``active`` and
+    ``state_bytes``, the recurrent state of the active slots; with
     ``engine.decode_inputs``, ``engine.decode_dispatch`` and
     ``engine.decode_wait``) and ``engine.sample``.  They land in an open
     ``jax.profiler`` trace beside the device's work, where the jitted
@@ -130,6 +139,9 @@ class ServingEngine:
         self.replans = 0
         self._decode_steps = 0
         self.cache = model.init_cache(max_batch, max_len)
+        # one slot's recurrent state, in bytes (0 where the model has none)
+        self._slot_state_bytes = sum(self.cache[k].nbytes // max_batch
+                                     for k in STATE_KEYS if k in self.cache)
         self.lengths = np.zeros((max_batch,), np.int32)
         self.slot_req: list[Request | None] = [None] * max_batch
         self.queue: deque[Request] = deque()
@@ -328,7 +340,9 @@ class ServingEngine:
                     logits, pcache = self._prefill_fn(plen)(
                         self.params, self._prefill_batch(req))
                 with host_span("engine.write_slot", tel, request=rid,
-                               plen=plen):
+                               plen=plen,
+                               state_bytes=self._written(pcache, STATE_KEYS),
+                               kv_bytes=self._written(pcache, KV_KEYS)):
                     self._write_slot(slot, pcache, plen)
                 with host_span("engine.first_token", tel, request=rid,
                                plen=plen):
@@ -351,13 +365,18 @@ class ServingEngine:
         batch["lengths"] = jnp.asarray([plen], jnp.int32)
         return batch
 
+    def _written(self, pcache: dict, keys: tuple) -> int:
+        """Bytes that writing ``pcache`` into a slot stores for ``keys``."""
+        return sum(pcache[k].size * self.cache[k].dtype.itemsize
+                   for k in keys if k in self.cache)
+
     def _write_slot(self, slot: int, pcache: dict, plen: int) -> None:
         """Copy a (L, 1, P, ...) prefill cache into slot ``slot`` of the
         engine cache (padded to max_len)."""
         new = {}
         for k in self.cache:
             dst, src = self.cache[k], pcache[k]
-            if k in ("k", "v", "xk", "xv"):
+            if k in KV_KEYS:
                 # (..., 1, P, H, D) → slot write at seq prefix
                 p = src.shape[-3]
                 new[k] = dst.at[..., slot, :p, :, :].set(src[..., 0, :p, :, :])
@@ -377,7 +396,9 @@ class ServingEngine:
             if self.active() == 0:
                 return
             self.state = State.EXECUTE
-            with host_span("engine.decode", tel, active=self.active()):
+            active = self.active()
+            with host_span("engine.decode", tel, active=active,
+                           state_bytes=active * self._slot_state_bytes):
                 with host_span("engine.decode_inputs", tel):
                     tokens = np.zeros((self.max_batch, 1), np.int32)
                     for s, req in enumerate(self.slot_req):

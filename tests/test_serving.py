@@ -508,3 +508,63 @@ def test_engine_without_recorder_records_nothing(small_lm):
     assert _serve_steps(eng) > 0 and len(eng.completed) == len(SPAN_PROMPTS)
     assert eng.telemetry is None and rec.events == []
     assert not hasattr(eng, "trace")
+
+
+@pytest.fixture(scope="module")
+def small_ssm():
+    cfg = get_config("mamba2-780m").reduced()
+    model = build_model(cfg)
+    return cfg, model, model.init(jax.random.PRNGKey(4))
+
+
+def _span_attrs(rec, name):
+    return [e.attrs for e in rec.events if e.name == name]
+
+
+def test_ssm_spans_carry_the_recurrent_state_bytes(small_ssm, tmp_path):
+    """A state-space engine's slot write stores one slot of ``h`` and of
+    the convolution's context and no keys or values; each decode step
+    carries the state of its active slots.  The attributes reach the
+    recorder and the profiler's trace alike."""
+    cfg, model, params = small_ssm
+    rec = TelemetryRecorder("engine")
+    eng = ServingEngine(model, params, max_batch=3, max_len=32,
+                        telemetry=rec)
+    h, conv = eng.cache["h"], eng.cache["conv"]
+    assert h.dtype == jnp.float32
+    slot = (h.size * 4 + conv.size * conv.dtype.itemsize) // 3
+    assert slot == (cfg.n_layers * cfg.ssm.n_heads(cfg.d_model)
+                    * cfg.ssm.head_dim * cfg.ssm.d_state * 4
+                    + cfg.n_layers * (cfg.ssm.conv_width - 1)
+                    * (cfg.ssm.d_inner(cfg.d_model) + 2 * cfg.ssm.d_state)
+                    * conv.dtype.itemsize)
+    spans = _profiled_spans(tmp_path, lambda: _serve_steps(eng))
+    writes = _span_attrs(rec, "engine.write_slot")
+    assert len(writes) == len(SPAN_PROMPTS)
+    assert all((w["state_bytes"], w["kv_bytes"]) == (slot, 0)
+               for w in writes)
+    decodes = _span_attrs(rec, "engine.decode")
+    assert decodes and all(d["state_bytes"] == d["active"] * slot
+                           for d in decodes)
+    assert {d["active"] for d in decodes} >= {1, 3}
+    traced = [s[3] for s in spans if s[0] == "engine.decode"]
+    assert [(int(t["active"]), int(t["state_bytes"])) for t in traced] == [
+        (d["active"], d["state_bytes"]) for d in decodes]
+    assert all(int(s[3]["state_bytes"]) == slot for s in spans
+               if s[0] == "engine.write_slot")
+
+
+def test_dense_spans_carry_the_kv_bytes_written(small_lm):
+    """A dense engine's slot write stores the prompt's keys and values and
+    no recurrent state; its decode steps carry none."""
+    cfg, model, params = small_lm
+    rec = TelemetryRecorder("engine")
+    eng = ServingEngine(model, params, max_batch=2, max_len=32,
+                        telemetry=rec)
+    _serve_steps(eng)
+    per_token = 2 * cfg.n_layers * cfg.n_kv_heads * cfg.hd * 2   # K and V
+    writes = _span_attrs(rec, "engine.write_slot")
+    assert [(w["plen"] * per_token, 0) for w in writes] == [
+        (w["kv_bytes"], w["state_bytes"]) for w in writes]
+    assert all(d["state_bytes"] == 0
+               for d in _span_attrs(rec, "engine.decode"))
