@@ -100,8 +100,16 @@ def ssd(x, dt, A, B, C, D, *, chunk=128, h0=None):
                        (A, D))
 
 
-def ssd_decode_step(h, x, dt, A, B, C, D):
+def ssd_decode_step(h, layer, x, dt, A, B, C, D):
     """One token's state update and readout, named ``ssd_decode_step`` in
-    the program's operations."""
+    the program's operations.  ``h`` is the stacked state of every layer,
+    (L, b, nh, hd, n); slice ``layer`` is updated in place and the stack
+    returned.  The readout reads the new slice back out of the updated
+    stack: were the old slice read by anything but the update, XLA would
+    copy the whole stack in every layer.  The state keeps its dtype."""
     with jax.named_scope("ssd_decode_step"):
-        return ref.ssd_decode_step(h, x, dt, A, B, C, D)
+        h_old = jax.lax.dynamic_index_in_dim(h, layer, keepdims=False)
+        update = ref.ssd_decode_update(h_old, x, dt, A, B).astype(h.dtype)
+        h = jax.lax.dynamic_update_index_in_dim(h, update, layer, 0)
+        h_new = jax.lax.dynamic_index_in_dim(h, layer, keepdims=False)
+        return ref.ssd_decode_readout(h_new, x, C, D), h

@@ -267,15 +267,29 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     return y.astype(x.dtype), h_final
 
 
+def ssd_decode_update(h: jax.Array, x: jax.Array, dt: jax.Array,
+                      A: jax.Array, B: jax.Array) -> jax.Array:
+    """One token's state update.  h: (b,nh,hd,n); x: (b,nh,hd); dt: (b,nh);
+    B: (b,n).  Returns h_new."""
+    dA = jnp.exp(dt * A[None, :])
+    dBx = jnp.einsum("bn,bhp->bhpn", B.astype(jnp.float32),
+                     x.astype(jnp.float32) * dt[..., None])
+    return h * dA[..., None, None] + dBx
+
+
+def ssd_decode_readout(h_new: jax.Array, x: jax.Array, C: jax.Array,
+                       D: jax.Array) -> jax.Array:
+    """One token's output from the updated state.  C: (b,n).  Returns y
+    (b,nh,hd)."""
+    y = jnp.einsum("bhpn,bn->bhp", h_new, C.astype(jnp.float32))
+    y = y + x.astype(jnp.float32) * D[None, :, None]
+    return y.astype(x.dtype)
+
+
 def ssd_decode_step(h: jax.Array, x: jax.Array, dt: jax.Array, A: jax.Array,
                     B: jax.Array, C: jax.Array, D: jax.Array
                     ) -> tuple[jax.Array, jax.Array]:
     """One-token SSM update.  h: (b,nh,hd,n); x: (b,nh,hd); dt: (b,nh);
     B,C: (b,n).  Returns (y (b,nh,hd), h_new)."""
-    dA = jnp.exp(dt * A[None, :])
-    dBx = jnp.einsum("bn,bhp->bhpn", B.astype(jnp.float32),
-                     x.astype(jnp.float32) * dt[..., None])
-    h_new = h * dA[..., None, None] + dBx
-    y = jnp.einsum("bhpn,bn->bhp", h_new, C.astype(jnp.float32))
-    y = y + x.astype(jnp.float32) * D[None, :, None]
-    return y.astype(x.dtype), h_new
+    h_new = ssd_decode_update(h, x, dt, A, B)
+    return ssd_decode_readout(h_new, x, C, D), h_new

@@ -282,9 +282,13 @@ def _causal_conv(xbc: jax.Array, w: jax.Array,
 
 
 def mamba_block(cfg: ArchConfig, p: dict, x: jax.Array, *, mode: str,
-                cache: dict | None = None
+                cache: dict | None = None, layer: jax.Array | None = None
                 ) -> tuple[jax.Array, dict]:
-    """One Mamba-2 mixer.  cache = {"h": (B,nh,hd,n), "conv": (B,cw-1,C)}."""
+    """One Mamba-2 mixer.  Prefill and training take no cache and return
+    this layer's state, {"h": (B,nh,hd,n), "conv": (B,cw-1,C)}.  Decode
+    takes the stacked state of every layer, {"h": (L,B,nh,hd,n), "conv":
+    (L,B,cw-1,C)}, updates slice ``layer`` of each in place and returns the
+    stacks."""
     spec = cfg.ssm
     b, t, d = x.shape
     di, n, nh = spec.d_inner(d), spec.d_state, spec.n_heads(d)
@@ -294,7 +298,9 @@ def mamba_block(cfg: ArchConfig, p: dict, x: jax.Array, *, mode: str,
     z, xs, B, C, dt = jnp.split(
         zxbcdt, [di, 2 * di, 2 * di + n, 2 * di + 2 * n], axis=-1)
     conv_in = jnp.concatenate([xs, B, C], axis=-1)
-    conv_state = None if cache is None else cache["conv"]
+    conv_state = (None if cache is None else
+                  jax.lax.dynamic_index_in_dim(cache["conv"], layer,
+                                               keepdims=False))
     conv_out, new_conv = _causal_conv(conv_in, p["conv"].astype(COMPUTE_DTYPE),
                                       conv_state)
     xs, B, C = jnp.split(conv_out, [di, di + n], axis=-1)
@@ -302,16 +308,17 @@ def mamba_block(cfg: ArchConfig, p: dict, x: jax.Array, *, mode: str,
                          + p["dt_bias"].astype(jnp.float32))   # (B,T,nh)
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
     xh = xs.reshape(b, t, nh, hd)
-    h0 = None if cache is None else cache["h"]
     if mode == "decode":
-        assert cache is not None
-        y, h_new = ops.ssd_decode_step(cache["h"], xh[:, 0], dt[:, 0], A,
-                                       B[:, 0], C[:, 0],
+        assert cache is not None and layer is not None
+        new_conv = jax.lax.dynamic_update_index_in_dim(
+            cache["conv"], new_conv, layer, 0)
+        y, h_new = ops.ssd_decode_step(cache["h"], layer, xh[:, 0], dt[:, 0],
+                                       A, B[:, 0], C[:, 0],
                                        p["D"].astype(jnp.float32))
         y = y[:, None]                                        # (B,1,nh,hd)
     else:
         y, h_new = ops.ssd(xh, dt, A, B, C, p["D"].astype(jnp.float32),
-                           chunk=spec.chunk, h0=h0)
+                           chunk=spec.chunk)
     y = y.reshape(b, t, di)
     y = rmsnorm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
                 p["norm"])

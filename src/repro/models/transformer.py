@@ -30,6 +30,10 @@ from . import layers as L
 from .config import ArchConfig
 
 CACHE_DTYPE = jnp.bfloat16
+#: cache entries held whole per slot (a state-space layer's recurrent state
+#: and its convolution's context); decode carries them through the layer
+#: scan and updates them in place
+STATE_KEYS = ("h", "conv")
 
 
 @jax.custom_vjp
@@ -130,7 +134,11 @@ def window_schedule(cfg: ArchConfig, kv_len: int) -> jax.Array | None:
 
 def init_cache(cfg: ArchConfig, batch: int, max_len: int,
                abstract: bool = False) -> dict:
-    """Stacked (leading L) decode cache."""
+    """Stacked (leading L) decode cache.  Keys and values (``k``, ``v``)
+    change one position a step and pass through the decode's layer scan as
+    inputs and outputs; the recurrent state (``STATE_KEYS``) changes whole
+    every step and is carried through the scan instead, each layer updating
+    its slice in place (see ``forward``)."""
     def mk(shape, dtype=CACHE_DTYPE):
         if abstract:
             return jax.ShapeDtypeStruct(shape, dtype)
@@ -155,14 +163,19 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int,
 
 def apply_layer(cfg: ArchConfig, p: dict, x: jax.Array, *, mode: str,
                 positions: jax.Array, window, layer_cache: dict | None,
-                lengths: jax.Array | None, moe_impl: str = "dense"
+                lengths: jax.Array | None, moe_impl: str = "dense",
+                layer: jax.Array | None = None
                 ) -> tuple[jax.Array, dict]:
+    """In decode, ``layer_cache`` holds this layer's keys and values and
+    the stacked recurrent state of every layer, whose slice ``layer`` is
+    updated in place."""
     new_cache: dict[str, Any] = {}
     h = L.apply_norm(cfg, p["ln1"], x)
     if cfg.family == "ssm":
         ssm_cache = (None if layer_cache is None else
                      {"h": layer_cache["h"], "conv": layer_cache["conv"]})
-        y, sc = L.mamba_block(cfg, p["ssm"], h, mode=mode, cache=ssm_cache)
+        y, sc = L.mamba_block(cfg, p["ssm"], h, mode=mode, cache=ssm_cache,
+                              layer=layer)
         new_cache.update(sc)
         return x + y, new_cache
 
@@ -176,7 +189,8 @@ def apply_layer(cfg: ArchConfig, p: dict, x: jax.Array, *, mode: str,
     if cfg.family == "hybrid":
         ssm_cache = (None if layer_cache is None else
                      {"h": layer_cache["h"], "conv": layer_cache["conv"]})
-        s, sc = L.mamba_block(cfg, p["ssm"], h, mode=mode, cache=ssm_cache)
+        s, sc = L.mamba_block(cfg, p["ssm"], h, mode=mode, cache=ssm_cache,
+                              layer=layer)
         new_cache.update(sc)
         a = (a + s) * 0.5                   # parallel heads, mean-fused
     x = x + a
@@ -206,6 +220,12 @@ def forward(cfg: ArchConfig, params: dict, tokens: jax.Array, *,
     mode="train"/"prefill": full sequence; prefill returns the built cache.
     mode="decode": T==1, requires ``cache`` + ``lengths`` (new token position
     = lengths-1).
+    The decode's layer scan takes the keys and values as inputs and gives
+    their new values as outputs; it carries the recurrent state
+    (``STATE_KEYS``) instead, each layer updating its slice of the stack in
+    place.  Given as inputs, the state's new value would be written to a
+    fresh stacked output, which XLA then copies whole into the donated
+    cache.
     ``logits_tail``: only unembed the last N positions (prefill: N=1).
     ``remat_group``: checkpoint every N layers instead of every layer —
     divides saved-activation memory by N at the cost of recomputing up to N
@@ -225,23 +245,29 @@ def forward(cfg: ArchConfig, params: dict, tokens: jax.Array, *,
     wsched = window_schedule(cfg, kv_len)
     return_cache = mode in ("prefill", "decode")
 
+    cache = cache or {}
+    state = {k: a for k, a in cache.items() if k in STATE_KEYS}
+    per_layer = {k: a for k, a in cache.items() if k not in STATE_KEYS}
+
     # window of -1 means "no window" — translate inside via where on mask:
     # the ref kernels accept traced windows; -1 disables via huge value.
     def body(carry, xs):
-        x = carry
-        p, w, lc = xs
+        x, state = carry
+        p, w, lc, i = xs
         w_eff = None if wsched is None else jnp.where(w < 0,
                                                       jnp.int32(2 ** 30), w)
+        lc = {**lc, **state} if mode == "decode" else None
         y, nc = apply_layer(cfg, p, x, mode=mode, positions=positions,
                             window=w_eff, layer_cache=lc, lengths=lengths,
-                            moe_impl=moe_impl)
+                            moe_impl=moe_impl, layer=i)
         y = shard_ctx.constrain_act(y)
-        return y, (nc if return_cache else None)
+        state = {k: nc.pop(k) for k in state}
+        return (y, state), (nc if return_cache else None)
 
     xs = (params["layers"],
           (wsched if wsched is not None
            else jnp.zeros((cfg.n_layers,), jnp.int32) - 1),
-          cache)
+          per_layer, jnp.arange(cfg.n_layers, dtype=jnp.int32))
     g = remat_group if (remat and remat_group > 1
                         and cfg.n_layers % remat_group == 0) else 1
 
@@ -249,18 +275,19 @@ def forward(cfg: ArchConfig, params: dict, tokens: jax.Array, *,
         # the barrier pins the checkpointed carry in bf16: without it XLA
         # hoists the backward pass's f32 convert out of the loop and
         # materialises an f32 copy of the whole residual stack (§Perf B)
-        carry = _pinned(carry)
-        return jax.lax.scan(body, carry, xs_g)
+        x, state = carry
+        return jax.lax.scan(body, (_pinned(x), state), xs_g)
 
     if remat:
         group_body = jax.checkpoint(
             group_body, policy=jax.checkpoint_policies.nothing_saveable)
     xs = jax.tree.map(
         lambda a: a.reshape((cfg.n_layers // g, g) + a.shape[1:]), xs)
-    x, new_cache = jax.lax.scan(group_body, x, xs)
-    if return_cache and new_cache is not None:
-        new_cache = jax.tree.map(
-            lambda a: a.reshape((cfg.n_layers,) + a.shape[2:]), new_cache)
+    (x, state), new_cache = jax.lax.scan(group_body, (x, state), xs)
+    if return_cache:
+        new_cache = {**jax.tree.map(
+            lambda a: a.reshape((cfg.n_layers,) + a.shape[2:]), new_cache),
+            **state}
     x = L.apply_norm(cfg, params["final_norm"], x)
     if logits_tail is not None:
         x = x[:, -logits_tail:]

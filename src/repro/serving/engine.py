@@ -31,13 +31,11 @@ from repro.core.fingerprint import dag_fingerprint
 from repro.core.objective import METRICS
 from repro.core.scheduler import State
 from repro.models.model import Model
+from repro.models.transformer import STATE_KEYS
 from repro.telemetry import active, host_span
 
 #: cache entries held per position of a slot (attention's keys and values)
 KV_KEYS = ("k", "v", "xk", "xv")
-#: cache entries held whole per slot (a state-space layer's recurrent state
-#: and its convolution's context)
-STATE_KEYS = ("h", "conv")
 
 
 @dataclasses.dataclass
@@ -105,8 +103,10 @@ class ServingEngine:
     ``engine.write_slot`` and ``engine.first_token`` per admitted request,
     each carrying ``request`` and ``plen``; the slot write also carries the
     bytes it stores, ``state_bytes`` of recurrent state and ``kv_bytes`` of
-    keys and values), ``engine.decode`` (carrying ``active`` and
-    ``state_bytes``, the recurrent state of the active slots; with
+    keys and values), ``engine.decode`` (carrying ``active``,
+    ``state_bytes``, the recurrent state of the active slots, and
+    ``in_place_bytes``, the cache the step updates in place in its donated
+    buffer, every slot's recurrent state; with
     ``engine.decode_inputs``, ``engine.decode_dispatch`` and
     ``engine.decode_wait``) and ``engine.sample``.  They land in an open
     ``jax.profiler`` trace beside the device's work, where the jitted
@@ -139,9 +139,11 @@ class ServingEngine:
         self.replans = 0
         self._decode_steps = 0
         self.cache = model.init_cache(max_batch, max_len)
-        # one slot's recurrent state, in bytes (0 where the model has none)
-        self._slot_state_bytes = sum(self.cache[k].nbytes // max_batch
-                                     for k in STATE_KEYS if k in self.cache)
+        # every slot's recurrent state, in bytes (0 where the model has
+        # none): the decode step carries it and updates it in place
+        self._in_place_bytes = sum(self.cache[k].nbytes
+                                   for k in STATE_KEYS if k in self.cache)
+        self._slot_state_bytes = self._in_place_bytes // max_batch
         self.lengths = np.zeros((max_batch,), np.int32)
         self.slot_req: list[Request | None] = [None] * max_batch
         self.queue: deque[Request] = deque()
@@ -398,7 +400,8 @@ class ServingEngine:
             self.state = State.EXECUTE
             active = self.active()
             with host_span("engine.decode", tel, active=active,
-                           state_bytes=active * self._slot_state_bytes):
+                           state_bytes=active * self._slot_state_bytes,
+                           in_place_bytes=self._in_place_bytes):
                 with host_span("engine.decode_inputs", tel):
                     tokens = np.zeros((self.max_batch, 1), np.int32)
                     for s, req in enumerate(self.slot_req):

@@ -1,6 +1,9 @@
 """Serving engine: continuous batching, slot reuse, and greedy-decode
 equivalence against a reference incremental loop."""
 
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -524,8 +527,9 @@ def _span_attrs(rec, name):
 def test_ssm_spans_carry_the_recurrent_state_bytes(small_ssm, tmp_path):
     """A state-space engine's slot write stores one slot of ``h`` and of
     the convolution's context and no keys or values; each decode step
-    carries the state of its active slots.  The attributes reach the
-    recorder and the profiler's trace alike."""
+    carries the state of its active slots, and updates every slot's state
+    in place.  The attributes reach the recorder and the profiler's trace
+    alike."""
     cfg, model, params = small_ssm
     rec = TelemetryRecorder("engine")
     eng = ServingEngine(model, params, max_batch=3, max_len=32,
@@ -547,16 +551,21 @@ def test_ssm_spans_carry_the_recurrent_state_bytes(small_ssm, tmp_path):
     assert decodes and all(d["state_bytes"] == d["active"] * slot
                            for d in decodes)
     assert {d["active"] for d in decodes} >= {1, 3}
+    assert all(d["in_place_bytes"] == h.nbytes + conv.nbytes == 3 * slot
+               for d in decodes)
     traced = [s[3] for s in spans if s[0] == "engine.decode"]
-    assert [(int(t["active"]), int(t["state_bytes"])) for t in traced] == [
-        (d["active"], d["state_bytes"]) for d in decodes]
+    assert [(int(t["active"]), int(t["state_bytes"]),
+             int(t["in_place_bytes"])) for t in traced] == [
+        (d["active"], d["state_bytes"], d["in_place_bytes"])
+        for d in decodes]
     assert all(int(s[3]["state_bytes"]) == slot for s in spans
                if s[0] == "engine.write_slot")
 
 
 def test_dense_spans_carry_the_kv_bytes_written(small_lm):
     """A dense engine's slot write stores the prompt's keys and values and
-    no recurrent state; its decode steps carry none."""
+    no recurrent state; its decode steps carry none and update no cache in
+    place."""
     cfg, model, params = small_lm
     rec = TelemetryRecorder("engine")
     eng = ServingEngine(model, params, max_batch=2, max_len=32,
@@ -566,5 +575,34 @@ def test_dense_spans_carry_the_kv_bytes_written(small_lm):
     writes = _span_attrs(rec, "engine.write_slot")
     assert [(w["plen"] * per_token, 0) for w in writes] == [
         (w["kv_bytes"], w["state_bytes"]) for w in writes]
-    assert all(d["state_bytes"] == 0
-               for d in _span_attrs(rec, "engine.decode"))
+    decodes = _span_attrs(rec, "engine.decode")
+    assert decodes and all((d["state_bytes"], d["in_place_bytes"]) == (0, 0)
+                           for d in decodes)
+
+
+def _copies_of(text: str, a) -> list[str]:
+    """The instructions of compiled HLO ``text`` that copy a whole array
+    of ``a``'s shape."""
+    dims = ",".join(map(str, a.shape))
+    return re.findall(rf"%\S*copy\S* = \w+\[{dims}\]", text)
+
+
+@pytest.mark.parametrize("arch", ["mamba2-780m", "hymba-1.5b"])
+def test_decode_updates_the_recurrent_state_in_place(arch):
+    """The engine's donated decode program carries the stacked ``h``
+    through its layer loop and updates it in place: no copy of the whole
+    stack (after the loop, or in every layer where the old slice has a
+    second reader), and no temporary as large as it.  Eight layers make
+    the state outweigh the step's other temporaries; the hybrid's keys and
+    values still pass through the loop's outputs, so its cache is kept
+    short."""
+    cfg = dataclasses.replace(get_config(arch).reduced(), n_layers=8)
+    model = build_model(cfg)
+    eng = ServingEngine(model, model.init(jax.random.PRNGKey(6)),
+                        max_batch=32, max_len=2)
+    batch = {"tokens": jnp.zeros((32, 1), jnp.int32),
+             "lengths": jnp.ones((32,), jnp.int32)}
+    compiled = eng._decode.lower(eng.params, eng.cache, batch).compile()
+    h = eng.cache["h"]
+    assert _copies_of(compiled.as_text(), h) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < h.nbytes

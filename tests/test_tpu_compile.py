@@ -9,6 +9,7 @@ tests skip.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -117,3 +118,31 @@ def test_gemma_decode_step_compiles_with_pallas(one_chip, monkeypatch):
     resident = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert resident < 16 * 2 ** 30
+
+
+def test_mamba2_decode_step_updates_the_state_in_place(one_chip,
+                                                       monkeypatch):
+    """The full-width state-space decode step at the benchmark's geometry
+    (bf16 weights, 32 slots of 1024) updates the stacked recurrent state
+    in the donated cache: no copy of the whole ``h`` or ``conv`` stack, no
+    temporary as large as ``h``, and no kernel (the step is plain XLA).
+    ``ops`` sees this process's CPU, so the test steers it."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    model = build_model(MAMBA)
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree)
+    params = on_chip(model.param_specs(jnp.bfloat16))
+    cache = on_chip(model.init_cache(32, 1024, abstract=True))
+    batch = on_chip({"tokens": jax.ShapeDtypeStruct((32, 1), jnp.int32),
+                     "lengths": jax.ShapeDtypeStruct((32,), jnp.int32)})
+    compiled = jax.jit(lambda p, c, b: model.apply_decode(p, c, b),
+                       donate_argnums=(1,)).lower(params, cache,
+                                                  batch).compile()
+    text = compiled.as_text()
+    for k in ("h", "conv"):
+        dims = ",".join(map(str, cache[k].shape))
+        assert not re.findall(rf"%\S*copy\S* = \w+\[{dims}\]", text), k
+    h_bytes = cache["h"].size * cache["h"].dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < h_bytes
+    assert "tpu_custom_call" not in text
